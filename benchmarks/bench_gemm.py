@@ -341,4 +341,6 @@ def csv_main() -> list[str]:
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+    compile_cache.enable()
     main()

@@ -49,4 +49,6 @@ def main() -> list[str]:
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+    compile_cache.enable()
     print("\n".join(main()))

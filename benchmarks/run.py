@@ -49,4 +49,6 @@ def main() -> int:
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+    compile_cache.enable()
     raise SystemExit(main())

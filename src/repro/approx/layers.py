@@ -40,7 +40,12 @@ def gemm(x: jax.Array, w,
     call ("auto" | "pallas" | "xla"); None keeps `spec.policy`.
     """
     if spec is None or spec.is_exact:
-        return jnp.einsum("...k,kn->...n", x, _as_weight(w, x.dtype))
+        # f32 accumulation out of the dot, rounded once: a row-parallel
+        # (K-sharded) weight then all-reduces f32 partial sums instead of
+        # bf16-rounded ones, so tensor-parallel serving matches one chip
+        return jnp.einsum("...k,kn->...n", x, _as_weight(w, x.dtype),
+                          preferred_element_type=jnp.float32
+                          ).astype(x.dtype)
     if policy is not None:
         spec = spec.with_policy(policy)
     if gemm_mod.is_prepared(w):

@@ -1,35 +1,28 @@
-"""Single home for every version-sensitive JAX API this codebase touches.
+"""Single home of the JAX spellings this codebase wraps.
 
-JAX's experimental surfaces (Pallas TPU params, AbstractMesh, make_mesh)
-have renamed or re-signatured across the 0.4.x -> 0.5+ line; instead of
-patching call sites each time, all drift is absorbed here behind stable
-functions.  Rules of the road:
+The repository is pinned to one JAX release (requirements.txt).  What
+stays here is what call sites must not spell themselves:
 
-* No module outside `repro.compat` may reference `pltpu.*CompilerParams`
-  or construct `jax.sharding.AbstractMesh` directly (enforced by
-  tests/test_compat.py).
-* Shims feature-probe (hasattr / trial construction), not version-compare,
-  wherever possible.
-* Importing this package never initializes JAX device state.
+* meshes, built with `Auto` axis types (`make_mesh`, `make_abstract_mesh`);
+* the Pallas TPU compiler-params class (`tpu_compiler_params`);
+* the backend probe that decides Mosaic vs interpret mode.
+
+No module outside `repro.compat` may reference `pltpu.*CompilerParams` or
+construct `jax.sharding.AbstractMesh` directly (enforced by
+tests/test_compat.py).  Importing this package never initializes JAX
+device state.
 """
 
-from repro.compat.mesh import make_abstract_mesh, make_mesh, shard_map_fn
-from repro.compat.pallas import (compiler_params_cls,
-                                 normalize_dimension_semantics,
+from repro.compat.mesh import make_abstract_mesh, make_mesh
+from repro.compat.pallas import (normalize_dimension_semantics,
                                  tpu_compiler_params)
-from repro.compat.version import (MIN_SUPPORTED, at_least, backend,
-                                  is_tpu_backend, jax_version)
+from repro.compat.version import backend, is_tpu_backend
 
 __all__ = [
-    "MIN_SUPPORTED",
-    "at_least",
     "backend",
-    "compiler_params_cls",
     "is_tpu_backend",
-    "jax_version",
     "make_abstract_mesh",
     "make_mesh",
     "normalize_dimension_semantics",
-    "shard_map_fn",
     "tpu_compiler_params",
 ]

@@ -1,13 +1,12 @@
-"""Mesh-construction shims.
+"""Mesh construction with the axis types this codebase is written for.
 
-Two APIs drifted across JAX releases:
-
-* `jax.sharding.AbstractMesh` — newer JAX takes `(axis_sizes, axis_names)`
-  as two sequences; 0.4.x takes a single tuple of `(name, size)` pairs.
-  The constructor style is feature-probed once (trial construction of a
-  1-element mesh) and cached.
-* `jax.make_mesh` — present since 0.4.35; older versions need a manual
-  device reshape into `jax.sharding.Mesh`.
+On the pinned JAX, `jax.make_mesh` defaults to `Explicit` axis types: array
+types then carry their shardings (`float32[512@model,128]`), and ops whose
+output sharding is ambiguous — a `jnp.take` on a vocab-sharded embedding,
+for one — raise `ShardingTypeError` even on a 1x1 mesh.  The model,
+serving and training code is written for GSPMD-propagated (`Auto`) axes,
+with shardings supplied by sharding/rules.py, so every mesh is built here
+with `Auto` axes.
 
 Everything here is callable-only (no module-level device probes): importing
 this module never initializes JAX device state.
@@ -15,90 +14,43 @@ this module never initializes JAX device state.
 
 from __future__ import annotations
 
-import functools
 import math
 from typing import Sequence
 
 import jax
-import numpy as np
-from jax.sharding import Mesh
-
-# AbstractMesh appeared mid-0.4.x; importing it unconditionally would break
-# this package on the oldest JAX the make_mesh fallback below exists for.
-_AbstractMesh = getattr(jax.sharding, "AbstractMesh", None)
+from jax.sharding import AbstractMesh, AxisType, Mesh
 
 
-@functools.lru_cache(maxsize=None)
-def _abstract_mesh_style() -> str:
-    """"split" = AbstractMesh(sizes, names); "pairs" = 0.4.x pair-tuples."""
-    try:
-        _AbstractMesh((1,), ("_compat_probe",))
-        return "split"
-    except TypeError:
-        pass
-    _AbstractMesh((("_compat_probe", 1),))
-    return "pairs"
-
-
-def make_abstract_mesh(axis_shapes: Sequence[int],
-                       axis_names: Sequence[str]):
-    """Device-free mesh for sharding-rule evaluation, on any JAX that has
-    AbstractMesh (raises a targeted error on ones that predate it)."""
-    if _AbstractMesh is None:
-        raise NotImplementedError(
-            f"jax {jax.__version__} has no jax.sharding.AbstractMesh; "
-            "build a concrete mesh via repro.compat.make_mesh instead")
+def _axes(axis_shapes: Sequence[int], axis_names: Sequence[str]
+          ) -> tuple[tuple[int, ...], tuple[str, ...]]:
     sizes = tuple(int(s) for s in axis_shapes)
     names = tuple(str(n) for n in axis_names)
     if len(sizes) != len(names):
         raise ValueError(f"{len(sizes)} axis sizes vs {len(names)} names")
-    if _abstract_mesh_style() == "split":
-        return _AbstractMesh(sizes, names)
-    return _AbstractMesh(tuple(zip(names, sizes)))
+    return sizes, names
 
 
-def shard_map_fn():
-    """`jax.shard_map` (0.6+) or `jax.experimental.shard_map.shard_map`
-    (0.4.x) — the per-device programming surface the mesh-aware kernel
-    dispatch uses.  Callers use the 0.4.x `check_rep` keyword; newer JAX
-    renamed it to `check_vma`, so the shim translates when the native
-    signature lacks `check_rep`."""
-    import inspect
-
-    if hasattr(jax, "shard_map"):
-        native = jax.shard_map
-    else:
-        from jax.experimental.shard_map import shard_map as native
-    try:
-        has_check_rep = "check_rep" in inspect.signature(native).parameters
-    except (TypeError, ValueError):  # C-level / wrapped signature
-        has_check_rep = True
-    if has_check_rep:
-        return native
-
-    def shard_map_compat(f, **kwargs):
-        if "check_rep" in kwargs:
-            kwargs["check_vma"] = kwargs.pop("check_rep")
-        return native(f, **kwargs)
-
-    return shard_map_compat
+def make_abstract_mesh(axis_shapes: Sequence[int],
+                       axis_names: Sequence[str]) -> AbstractMesh:
+    """Device-free mesh (Auto axes) for sharding-rule evaluation."""
+    sizes, names = _axes(axis_shapes, axis_names)
+    return AbstractMesh(sizes, names, (AxisType.Auto,) * len(sizes))
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
               devices: Sequence | None = None) -> Mesh:
-    """`jax.make_mesh` where available, manual Mesh construction otherwise.
+    """`jax.make_mesh` with `Auto` axis types.
 
-    With `devices=None` on a make_mesh-capable JAX this defers entirely to
-    jax.make_mesh (which picks a contiguous, locality-aware device order);
-    the fallback uses jax.devices() order.
-    """
-    sizes = tuple(int(s) for s in axis_shapes)
-    names = tuple(str(n) for n in axis_names)
+    With `devices=None` JAX picks a contiguous, locality-aware device
+    order; an explicit `devices` list is used from its head (the first
+    prod(axis_shapes) entries), so callers can pin e.g. one chip of four."""
+    sizes, names = _axes(axis_shapes, axis_names)
     n = math.prod(sizes)
-    if devices is None and hasattr(jax, "make_mesh"):
-        return jax.make_mesh(sizes, names)
-    devs = list(devices) if devices is not None else jax.devices()
-    if len(devs) < n:
-        raise ValueError(f"mesh {dict(zip(names, sizes))} needs {n} devices, "
-                         f"have {len(devs)}")
-    return Mesh(np.asarray(devs[:n], dtype=object).reshape(sizes), names)
+    if devices is not None:
+        devices = list(devices)
+        if len(devices) < n:
+            raise ValueError(f"mesh {dict(zip(names, sizes))} needs {n} "
+                             f"devices, have {len(devices)}")
+        devices = devices[:n]
+    return jax.make_mesh(sizes, names, (AxisType.Auto,) * len(sizes),
+                         devices=devices)

@@ -1,10 +1,8 @@
-"""Pallas TPU API shims.
+"""Pallas TPU compiler-params spelling.
 
-JAX renamed the TPU compiler-params dataclass across releases
-(`pltpu.TPUCompilerParams` on 0.4.x / early 0.5.x, `pltpu.CompilerParams`
-after the rename; very old versions took a plain dict keyed by backend).
-Kernel modules must not spell any of these directly — they call
-`tpu_compiler_params(...)` and get whatever the installed JAX accepts.
+Kernel modules do not spell `pltpu.CompilerParams` directly — the class
+has been renamed before (`TPUCompilerParams`) — they call
+`tpu_compiler_params(...)`, so a future rename is absorbed here.
 
 Dimension-semantics strings are normalized too: the Mosaic vocabulary is
 ("parallel", "arbitrary"); "sequential" is accepted as an alias for
@@ -23,10 +21,6 @@ _DIM_SEMANTICS_ALIASES = {
     "sequential": "arbitrary",
 }
 
-# Feature probe, newest spelling first.
-_PARAMS_CLS = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams", None)
-
 
 def normalize_dimension_semantics(sem: Sequence[str]) -> tuple[str, ...]:
     """Map each grid-dimension semantic onto the Mosaic vocabulary."""
@@ -42,21 +36,9 @@ def normalize_dimension_semantics(sem: Sequence[str]) -> tuple[str, ...]:
 
 
 def tpu_compiler_params(*, dimension_semantics: Sequence[str] | None = None,
-                        **kwargs: Any) -> Any:
-    """Build the `compiler_params=` argument for a TPU `pl.pallas_call`.
-
-    Returns the params dataclass the installed JAX exposes; on ancient
-    versions with neither class, falls back to the dict form pallas_call
-    accepted there.
-    """
+                        **kwargs: Any) -> pltpu.CompilerParams:
+    """Build the `compiler_params=` argument for a TPU `pl.pallas_call`."""
     if dimension_semantics is not None:
         kwargs["dimension_semantics"] = \
             normalize_dimension_semantics(dimension_semantics)
-    if _PARAMS_CLS is None:
-        return dict(mosaic=kwargs)
-    return _PARAMS_CLS(**kwargs)
-
-
-def compiler_params_cls() -> Any:
-    """The resolved params class (None on dict-form JAX). For tests/docs."""
-    return _PARAMS_CLS
+    return pltpu.CompilerParams(**kwargs)

@@ -1,46 +1,10 @@
-"""Version probe for the installed JAX.
-
-Every shim in this package keys off either the parsed version tuple or a
-direct feature probe (hasattr / trial construction).  Feature probes are
-preferred — they survive backports and dev builds whose version strings
-don't parse cleanly — but the tuple is exposed for docs/diagnostics and
-coarse gating.
-"""
+"""Backend probe for the installed JAX."""
 
 from __future__ import annotations
 
 import functools
 
 import jax
-
-#: Oldest JAX this codebase is tested against (see README "Supported JAX
-#: versions").  Not enforced at import time; compat probes do the real work.
-MIN_SUPPORTED = (0, 4, 30)
-
-
-@functools.lru_cache(maxsize=None)
-def jax_version() -> tuple[int, ...]:
-    """Installed JAX version as a tuple of ints, e.g. (0, 4, 37).
-
-    Non-numeric suffixes (".dev", "rc1") are dropped from the component in
-    which they appear; parsing never raises.
-    """
-    parts: list[int] = []
-    for piece in jax.__version__.split("."):
-        digits = ""
-        for ch in piece:
-            if not ch.isdigit():
-                break
-            digits += ch
-        if not digits:
-            break
-        parts.append(int(digits))
-    return tuple(parts[:3])
-
-
-def at_least(*want: int) -> bool:
-    """True when the installed JAX is >= the given version components."""
-    return jax_version() >= tuple(want)
 
 
 @functools.lru_cache(maxsize=None)

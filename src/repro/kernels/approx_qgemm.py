@@ -55,7 +55,7 @@ SKINNY_MAX_M = 32
 
 #: Bump when a kernel's schedule/layout changes in a way that invalidates
 #: measured tile timings (kernels/autotune.py keys its cache on this).
-KERNEL_VERSION = 2
+KERNEL_VERSION = 3
 
 
 def choose_blocks(m: int, k: int, n: int, bm: int | None = None,
@@ -189,6 +189,46 @@ def approx_qgemm_stacked(a_stack: jax.Array, b_stack: jax.Array,
 # fused kernel: raw operands in, table map + trunc mask in-kernel
 # ---------------------------------------------------------------------------
 
+def _lane_table_map(lo, hi, idx):
+    """Map every element of `idx` (rows, cols) int32 in [0, 256) through a
+    256-entry table given as its two 128-lane halves `lo`/`hi` (1, 128)
+    int32, in a form Mosaic lowers.
+
+    Mosaic has no 1-D gather (`jnp.take` on the table is refused), but it
+    does lower a 2-D lane gather: `take_along_axis(x, i, axis=1)` with x
+    and i both (rows, 128).  So each half is broadcast down the rows,
+    every 128-lane chunk of the index is looked up in both halves on
+    `idx & 127`, and the half is picked by `idx >= 128`.  `cols` must be a
+    multiple of 128 (every block shape is); fewer than 8 rows are padded
+    to one sublane tile (a 1-row gather does not lower).  Exact integer
+    lookups: the result equals `jnp.take` bit for bit."""
+    rows, cols = idx.shape
+    if rows < 8:
+        pad = jnp.zeros((8 - rows, cols), idx.dtype)
+        return _lane_table_map(lo, hi, jnp.concatenate([idx, pad]))[:rows]
+    lo = jnp.broadcast_to(lo, (rows, 128))
+    hi = jnp.broadcast_to(hi, (rows, 128))
+    lane = jnp.bitwise_and(idx, 127)
+    upper = idx >= 128
+    chunks = []
+    for c in range(0, cols, 128):
+        li = lane[:, c:c + 128]
+        chunks.append(jnp.where(
+            upper[:, c:c + 128],
+            jnp.take_along_axis(hi, li, axis=1),
+            jnp.take_along_axis(lo, li, axis=1)))
+    out = chunks[0] if len(chunks) == 1 else jnp.concatenate(chunks, axis=1)
+    return out.astype(jnp.int8)
+
+
+def _table_halves(tbl_ref, r: int):
+    """Row r of an (R, 256) int8 table ref as two (1, 128) int32 halves.
+    Each half is loaded on its own: a lane slice at offset 128 of a loaded
+    row would reach the broadcast with a layout Mosaic refuses."""
+    return tuple(tbl_ref[pl.ds(r, 1), pl.ds(h, 128)].astype(jnp.int32)
+                 for h in (0, 128))
+
+
 def _correction_dots(a, b, fu_ref, fv_ref, acc_ref, in_k, *, n_corr: int,
                      unroll: int):
     """Table-map + matmul the `n_corr` correction planes into acc_ref[1:].
@@ -205,11 +245,11 @@ def _correction_dots(a, b, fu_ref, fv_ref, acc_ref, in_k, *, n_corr: int,
         u = min(unroll, n_corr - r0)
         uas, vbs = [], []
         for r in range(r0, r0 + u):
-            ua = jnp.take(fu_ref[r], idx_a, axis=0)
+            ua = _lane_table_map(*_table_halves(fu_ref, r), idx_a)
             if in_k is not None:
                 ua = jnp.where(in_k, ua, jnp.int8(0))
             uas.append(ua)
-            vbs.append(jnp.take(fv_ref[r], idx_b, axis=0))
+            vbs.append(_lane_table_map(*_table_halves(fv_ref, r), idx_b))
         if u == 1:
             acc_ref[r0 + 1] += jnp.dot(uas[0], vbs[0],
                                        preferred_element_type=jnp.int32)

@@ -219,6 +219,19 @@ class Candidate:
     skinny: bool = False
 
 
+def _ranking_peaks():
+    """Chip peaks the candidate ranking uses: the local chip's on a TPU
+    (an unknown kind raises), the v5e target's elsewhere — off-TPU the
+    kernels run in interpret mode and the ranking only prunes the search
+    for the chip the repository targets."""
+    import jax
+
+    from repro.roofline import analysis as rfa
+    dev = jax.devices()[0]
+    kind = dev.device_kind if dev.platform == "tpu" else rfa.V5E_KIND
+    return rfa.chip_peaks(kind)
+
+
 def candidate_plans(m: int, k: int, n: int, n_planes: int, *,
                     vmem_budget: int,
                     max_candidates: int = MAX_MEASURED_CANDIDATES
@@ -229,6 +242,8 @@ def candidate_plans(m: int, k: int, n: int, n_planes: int, *,
     Plane-unroll only enters the space when there are >= 2 correction
     planes to group; the skinny kernel only when m is decode-shaped."""
     from repro.roofline import analysis as rfa
+
+    peaks = _ranking_peaks()
 
     unrolls = [u for u in UNROLL_CANDIDATES if u <= max(n_planes - 1, 1)]
     seen: set[Candidate] = set()
@@ -244,8 +259,9 @@ def candidate_plans(m: int, k: int, n: int, n_planes: int, *,
             vmem = qk.fused_vmem_bytes(c.bm, c.bk, c.bn, n_planes)
         if vmem > vmem_budget:
             return
-        cost = rfa.gemm_path_cost("fused", m, k, n, n_planes, bm=c.bm,
-                                  bk=c.bk, bn=c.bn, skinny=c.skinny)
+        cost = rfa.gemm_path_cost("fused", m, k, n, n_planes, peaks=peaks,
+                                  bm=c.bm, bk=c.bk, bn=c.bn,
+                                  skinny=c.skinny)
         scored.append((cost.time_s, c))
 
     kb = [b for b in BK_CANDIDATES if b < 2 * k] or [BK_CANDIDATES[0]]

@@ -230,9 +230,15 @@ def _tuned_plan(m: int, k: int, n: int, mode: str, rank: int,
 
 def _roofline_plan(m: int, k: int, n: int, n_planes: int,
                    bm: int, bk: int, bn: int) -> GemmPlan:
-    """On-TPU, no measurement: the roofline model's predicted winner."""
+    """On-TPU, no measurement: the roofline model's predicted winner at
+    the local chip's published peaks (a chip kind without an entry in
+    `roofline.analysis.CHIP_PEAKS` raises)."""
+    import jax
+
     from repro.kernels import approx_qgemm as qk
     from repro.roofline import analysis as rfa
+
+    peaks = rfa.chip_peaks(jax.devices()[0].device_kind)
 
     skinny = m <= qk.SKINNY_MAX_M
     if skinny:
@@ -243,9 +249,9 @@ def _roofline_plan(m: int, k: int, n: int, n_planes: int,
     if not _fused_admissible(m, k, n, n_planes, skinny=skinny,
                              bm=fbm, bk=fbk, bn=fbn):
         return GemmPlan("xla", bm, bk, bn, source="roofline")
-    winner, _ = rfa.predicted_gemm_winner(m, k, n, n_planes, bm=fbm,
-                                          bk=fbk, bn=fbn, skinny=skinny,
-                                          on_tpu=True)
+    winner, _ = rfa.predicted_gemm_winner(m, k, n, n_planes, peaks=peaks,
+                                          bm=fbm, bk=fbk, bn=fbn,
+                                          skinny=skinny)
     if winner == "fused":
         return GemmPlan("fused", fbm, fbk, fbn, skinny=skinny,
                         source="roofline")

@@ -17,7 +17,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.approx import gemm as gemm_mod
 from repro.kernels import approx_qgemm as qk
 from repro.kernels import dispatch
@@ -155,14 +154,13 @@ def approx_qgemm_tp(a_q: jax.Array, b_q: jax.Array,
     n = b_q.shape[1]
     tp = dispatch.tp_degree(mesh)
     assert tp > 1 and n % tp == 0, (n, tp)
-    shard_map = compat.shard_map_fn()
 
     def per_shard(a, b):
         return approx_qgemm(a, b, spec, fused=fused)
 
-    run = shard_map(per_shard, mesh=mesh,
-                    in_specs=(P(), P(None, axis)),
-                    out_specs=P(None, axis), check_rep=False)
+    run = jax.shard_map(per_shard, mesh=mesh,
+                        in_specs=(P(), P(None, axis)),
+                        out_specs=P(None, axis), check_vma=False)
     return run(a_q, b_q)
 
 
@@ -176,10 +174,9 @@ def approx_qgemm_replicated(a_q: jax.Array, b_q: jax.Array,
     partitioning either way)."""
     from jax.sharding import PartitionSpec as P
 
-    shard_map = compat.shard_map_fn()
-    run = shard_map(
+    run = jax.shard_map(
         lambda a, b: approx_qgemm(a, b, spec, fused=fused), mesh=mesh,
-        in_specs=(P(), P()), out_specs=P(), check_rep=False)
+        in_specs=(P(), P()), out_specs=P(), check_vma=False)
     return run(a_q, b_q)
 
 

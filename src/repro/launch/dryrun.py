@@ -32,6 +32,10 @@ from repro.roofline import analysis as roofline
 from repro.sharding import rules
 from repro.train import train_step as ts
 
+#: The chip the production meshes stand for: 256/512 placeholder devices
+#: are modelled as TPU v5e chips (its published peaks price the terms).
+TARGET_DEVICE_KIND = roofline.V5E_KIND
+
 
 def _sds_with_sharding(tree, shardings):
     return jax.tree_util.tree_map(
@@ -162,7 +166,9 @@ def run_cell(arch: str, shape_name: str, mesh, mesh_name: str,
             cfg, shape, mesh_shape, accum=accum,
             moment_bytes=2.2 if big else 8.0)
         mem["accum_steps"] = accum
-        terms = roofline.terms_from_compiled(compiled, cfg, shape, chips)
+        terms = roofline.terms_from_compiled(
+            compiled, cfg, shape, chips,
+            roofline.chip_peaks(TARGET_DEVICE_KIND))
         res = CellResult(arch, shape_name, mesh_name, ok=True,
                          compile_s=dt, memory=mem,
                          roofline=terms.as_dict())

@@ -20,10 +20,12 @@ import numpy as np
 
 from repro import configs
 from repro.data import synthetic
+from repro.launch import compile_cache
 from repro.serving import Engine, Request, SamplingParams
 
 
 def main(argv=None) -> int:
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="tinyllama-1.1b")
     ap.add_argument("--reduced", action="store_true")

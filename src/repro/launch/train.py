@@ -21,6 +21,7 @@ import numpy as np
 
 from repro import configs
 from repro.data import synthetic
+from repro.launch import compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.train import checkpoint as ckpt
 from repro.train import fault
@@ -28,6 +29,7 @@ from repro.train import train_step as ts
 
 
 def main(argv=None) -> int:
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="tinyllama-1.1b")
     ap.add_argument("--reduced", action="store_true",
@@ -118,6 +120,9 @@ def main(argv=None) -> int:
             return 0
     if mgr is not None:
         mgr.save(state, args.steps, blocking=True)
+    if not losses:  # resumed from a checkpoint of the last step
+        print(f"[train] done: no steps left after step {start_step}")
+        return 0
     first = np.mean(losses[:5]) if len(losses) >= 5 else losses[0]
     last = np.mean(losses[-5:])
     print(f"[train] done: loss {first:.4f} -> {last:.4f} "

@@ -6,8 +6,9 @@
 
 HLO_FLOPs / HLO_bytes come from compiled.cost_analysis(); collective bytes
 are parsed out of the post-SPMD optimized HLO text (operand sizes of every
-all-gather / all-reduce / reduce-scatter / all-to-all / collective-permute),
-per the brief.  Hardware constants: TPU v5e-class chip.
+all-gather / all-reduce / reduce-scatter / all-to-all / collective-permute).
+The peaks come from one table keyed by `jax.Device.device_kind`; a device
+that is not in it is an error, never a default.
 """
 
 from __future__ import annotations
@@ -15,10 +16,38 @@ from __future__ import annotations
 import dataclasses
 import re
 
-# --- hardware constants (TPU v5e-class target) -------------------------------
-PEAK_FLOPS_BF16 = 197e12          # per chip
-HBM_BW = 819e9                    # bytes/s per chip
-ICI_LINK_BW = 50e9                # bytes/s per link
+
+# --- published per-chip peaks, keyed by device_kind ---------------------------
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    """Published peak rates of one chip."""
+    bf16_flops: float     # FLOP/s, bf16 MXU
+    int8_ops: float       # OP/s, int8 MXU
+    hbm_bw: float         # bytes/s
+    ici_link_bw: float    # bytes/s per inter-chip link
+
+
+#: Source: Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16,
+#: 393 TOP/s int8, 16 GB HBM at 819 GB/s, 1,600 Gbit/s inter-chip
+#: interconnect over 4 links (50 GB/s each).
+V5E_KIND = "TPU v5 lite"
+CHIP_PEAKS = {
+    V5E_KIND: ChipPeaks(bf16_flops=197e12, int8_ops=393e12, hbm_bw=819e9,
+                        ici_link_bw=50e9),
+}
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    """Peaks of the chip JAX reports as `device_kind`; raises for a kind
+    with no published entry in CHIP_PEAKS."""
+    try:
+        return CHIP_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; add it "
+            f"to CHIP_PEAKS (known: {sorted(CHIP_PEAKS)})") from None
+
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2,
@@ -31,8 +60,6 @@ COLLECTIVE_KINDS = ("all-gather", "all-reduce", "reduce-scatter",
                     "all-to-all", "collective-permute")
 
 # --- approximate-GEMM kernel-path model (consumed by kernels/autotune) -------
-#: int8 MXU peak: 2x the bf16 rate on v5e-class parts.
-PEAK_OPS_INT8 = 2 * PEAK_FLOPS_BF16
 #: VPU table-gather throughput (elements/s): the fused kernel's per-plane
 #: (256,)-table maps run on the VPU, 8x128 lanes at ~940 MHz.
 GATHER_ELEMS_PER_S = 0.9e12
@@ -62,16 +89,17 @@ class GemmPathCost:
     hbm_bytes: float          # operand + intermediate + output traffic
     gather_elems: float       # in-kernel VPU table-map element count
     grid_steps: int           # pallas grid size (0 for the XLA path)
+    peaks: ChipPeaks
 
     @property
     def compute_s(self) -> float:
-        mxu = 2.0 * self.mac_ops / PEAK_OPS_INT8
+        mxu = 2.0 * self.mac_ops / self.peaks.int8_ops
         vpu = self.gather_elems / GATHER_ELEMS_PER_S
         return mxu + vpu
 
     @property
     def memory_s(self) -> float:
-        return self.hbm_bytes / HBM_BW
+        return self.hbm_bytes / self.peaks.hbm_bw
 
     @property
     def time_s(self) -> float:
@@ -89,10 +117,10 @@ class GemmPathCost:
 
 
 def gemm_path_cost(path: str, m: int, k: int, n: int, n_planes: int, *,
-                   bm: int = 256, bk: int = 512, bn: int = 256,
-                   skinny: bool = False) -> GemmPathCost:
+                   peaks: ChipPeaks, bm: int = 256, bk: int = 512,
+                   bn: int = 256, skinny: bool = False) -> GemmPathCost:
     """Roofline terms for an (m, k, n) approximate GEMM with `n_planes`
-    operand planes on `path` at tile (bm, bk, bn).
+    operand planes on `path` at tile (bm, bk, bn), on a chip with `peaks`.
 
     `skinny=True` models the decode-specialized kernel: the whole (un-
     padded) M rides in every grid step, so a batch-of-8 decode GEMM does
@@ -107,7 +135,8 @@ def gemm_path_cost(path: str, m: int, k: int, n: int, n_planes: int, *,
         mapped = r * (m * k + k * n)
         traffic = (m * k + k * n) + 2 * mapped + n_planes * (m * k + k * n) \
             + (1 + 2 * r) * 4 * m * n
-        return GemmPathCost(path, m * k * n * n_planes, traffic, 0.0, 0)
+        return GemmPathCost(path, m * k * n * n_planes, traffic, 0.0, 0,
+                            peaks)
     kp, np_ = _ceil_to(k, bk), _ceil_to(n, bn)
     if skinny:
         mp, grid_m = m, 1
@@ -124,29 +153,25 @@ def gemm_path_cost(path: str, m: int, k: int, n: int, n_planes: int, *,
         tables = 2 * 256 * r
         gathers = float(r) * grid * (mp // grid_m * bk + bk * bn)
         return GemmPathCost(path, mac, a_reads + b_reads + tables + out,
-                            gathers, grid)
+                            gathers, grid, peaks)
     # stacked: ops.build_stacks writes (and the kernel re-reads) per-plane
     # operand copies through HBM
     stack_build = n_planes * (m * k + k * n) + (m * k + k * n)
     return GemmPathCost(path, mac,
                         stack_build + n_planes * (a_reads + b_reads) + out,
-                        0.0, grid)
+                        0.0, grid, peaks)
 
 
 def predicted_gemm_winner(m: int, k: int, n: int, n_planes: int, *,
-                          bm: int = 256, bk: int = 512, bn: int = 256,
-                          skinny: bool = False,
-                          on_tpu: bool = True) -> tuple[str, dict]:
-    """(winner path, per-path predicted seconds) for an approximate GEMM.
-
-    Off-TPU the Pallas kernels run interpret mode — a correctness
-    vehicle, orders of magnitude off — so the prediction pins XLA unless
-    a measurement (tuning cache) says otherwise."""
-    costs = {p: gemm_path_cost(p, m, k, n, n_planes, bm=bm, bk=bk, bn=bn,
+                          peaks: ChipPeaks, bm: int = 256, bk: int = 512,
+                          bn: int = 256, skinny: bool = False
+                          ) -> tuple[str, dict]:
+    """(winner path, per-path predicted seconds) for an approximate GEMM
+    on a chip with `peaks`."""
+    costs = {p: gemm_path_cost(p, m, k, n, n_planes, peaks=peaks, bm=bm,
+                               bk=bk, bn=bn,
                                skinny=skinny and p == "fused").time_s
              for p in GEMM_PATHS}
-    if not on_tpu:
-        return "xla", costs
     return min(costs, key=costs.get), costs
 
 # matches e.g.  f32[16,4096,128]{2,1,0}  or  bf16[]  (scalars)
@@ -189,6 +214,7 @@ class RooflineTerms:
     collectives: dict              # per-kind bytes
     chips: int
     model_flops: float             # 6*N*D (or inference analogue)
+    peaks: ChipPeaks               # the chip the terms are modelled for
     # Pallas-kernel deployment model: traffic of vmem_kernel-tagged scopes
     # (materialized by the XLA-CPU lowering, VMEM-resident in the Mosaic
     # kernel) and the kernel's true HBM I/O to swap in instead.
@@ -197,11 +223,11 @@ class RooflineTerms:
 
     @property
     def compute_s(self) -> float:
-        return self.flops / (self.chips * PEAK_FLOPS_BF16)
+        return self.flops / (self.chips * self.peaks.bf16_flops)
 
     @property
     def memory_s(self) -> float:
-        return self.hbm_bytes / (self.chips * HBM_BW)
+        return self.hbm_bytes / (self.chips * self.peaks.hbm_bw)
 
     @property
     def hbm_bytes_kernel_adj(self) -> float:
@@ -211,18 +237,18 @@ class RooflineTerms:
 
     @property
     def memory_kernel_adj_s(self) -> float:
-        return self.hbm_bytes_kernel_adj / (self.chips * HBM_BW)
+        return self.hbm_bytes_kernel_adj / (self.chips * self.peaks.hbm_bw)
 
     @property
     def roofline_fraction_kernel_adj(self) -> float:
-        ideal = self.model_flops / (self.chips * PEAK_FLOPS_BF16)
+        ideal = self.model_flops / (self.chips * self.peaks.bf16_flops)
         worst = max(self.compute_s, self.memory_kernel_adj_s,
                     self.collective_s)
         return ideal / worst if worst > 0 else 0.0
 
     @property
     def collective_s(self) -> float:
-        return self.collective_bytes / (self.chips * ICI_LINK_BW)
+        return self.collective_bytes / (self.chips * self.peaks.ici_link_bw)
 
     @property
     def bottleneck(self) -> str:
@@ -240,7 +266,7 @@ class RooflineTerms:
     def roofline_fraction(self) -> float:
         """Useful-compute fraction of the step's roofline-limited time:
         model_flops/(chips*peak) / max(term)."""
-        ideal = self.model_flops / (self.chips * PEAK_FLOPS_BF16)
+        ideal = self.model_flops / (self.chips * self.peaks.bf16_flops)
         worst = max(self.compute_s, self.memory_s, self.collective_s)
         return ideal / worst if worst > 0 else 0.0
 
@@ -301,7 +327,8 @@ def kernel_io_bytes_for_cell(cfg, shape) -> float:
     return n_attn * passes * (2 * qo + kv)
 
 
-def terms_from_compiled(compiled, cfg, shape, chips: int) -> RooflineTerms:
+def terms_from_compiled(compiled, cfg, shape, chips: int,
+                        peaks: ChipPeaks) -> RooflineTerms:
     """Preferred path: the while-aware HLO module analyzer (hlo_parse.py).
     XLA's cost_analysis undercounts scanned layers (bodies counted once) —
     it is recorded in the dry-run JSON for cross-checking only."""
@@ -318,7 +345,7 @@ def terms_from_compiled(compiled, cfg, shape, chips: int) -> RooflineTerms:
         collective_bytes=stats.collective_bytes * chips,
         collectives={k: v * chips for k, v in stats.collectives.items()},
         chips=chips, model_flops=model_flops_for_cell(cfg, shape),
-        tagged_bytes=stats.tagged_traffic_bytes * chips,
+        peaks=peaks, tagged_bytes=stats.tagged_traffic_bytes * chips,
         kernel_io_bytes=kernel_io_bytes_for_cell(cfg, shape))
 
 

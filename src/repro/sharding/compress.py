@@ -17,7 +17,6 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 INT8_MAX = 127.0
 
@@ -103,8 +102,8 @@ def make_compressed_allreduce_fn(mesh: Mesh, axis: str = "data"):
     """shard_map-wrapped compressed all-reduce over one mesh axis, for
     replicated-along-`axis` tensors."""
     @functools.partial(
-        shard_map, mesh=mesh, in_specs=P(), out_specs=P(),
-        check_rep=False)
+        jax.shard_map, mesh=mesh, in_specs=P(), out_specs=P(),
+        check_vma=False)
     def fn(x):
         return compressed_allreduce(x, axis) / jax.lax.psum(1, axis)
 

@@ -16,7 +16,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def pipeline_apply(stage_fn: Callable, stage_params, x_mb: jax.Array,
@@ -34,9 +33,9 @@ def pipeline_apply(stage_fn: Callable, stage_params, x_mb: jax.Array,
     pspec_params = jax.tree_util.tree_map(lambda _: P(axis), stage_params)
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(pspec_params, P()), out_specs=P(),
-        check_rep=False)
+        check_vma=False)
     def run(params, xs):
         idx = jax.lax.axis_index(axis)
         local_params = jax.tree_util.tree_map(lambda p: p[0], params)

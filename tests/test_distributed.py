@@ -26,10 +26,10 @@ def run_devices(code: str, n: int = 8, timeout: int = 900) -> str:
 
 
 def test_param_pspec_rules():
-    import jax
     from jax.sharding import PartitionSpec as P
+    from repro.compat import make_mesh
     from repro.sharding import rules
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
 
     class K:  # fake DictKey
         def __init__(self, k):
@@ -234,15 +234,15 @@ def test_compressed_allreduce_matches_psum():
     run_devices("""
         import jax, jax.numpy as jnp, numpy as np, functools
         from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from repro.compat import make_mesh
         from repro.sharding import compress
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         rng = np.random.default_rng(0)
         xs = jnp.asarray(rng.standard_normal((8, 4096)), jnp.float32)
 
-        @functools.partial(shard_map, mesh=mesh, in_specs=P("data"),
-                           out_specs=P("data"), check_rep=False)
+        @functools.partial(jax.shard_map, mesh=mesh, in_specs=P("data"),
+                           out_specs=P("data"), check_vma=False)
         def f(x):
             local = x[0]
             s = compress.compressed_allreduce(local, "data")
@@ -265,15 +265,15 @@ def test_error_feedback_reduces_bias():
     run_devices("""
         import jax, jax.numpy as jnp, numpy as np, functools
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from repro.compat import make_mesh
         from repro.sharding import compress
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         rng = np.random.default_rng(1)
         g = jnp.asarray(rng.standard_normal((8, 1024)), jnp.float32)
 
-        @functools.partial(shard_map, mesh=mesh, in_specs=(P("data"), P("data")),
-                           out_specs=(P("data"), P("data")), check_rep=False)
+        @functools.partial(jax.shard_map, mesh=mesh, in_specs=(P("data"), P("data")),
+                           out_specs=(P("data"), P("data")), check_vma=False)
         def step(gs, es):
             out, e2 = compress.ef_compressed_allreduce(gs[0], es[0], "data")
             return out[None], e2[None]
@@ -295,9 +295,10 @@ def test_error_feedback_reduces_bias():
 def test_pipeline_matches_sequential():
     run_devices("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.compat import make_mesh
         from repro.sharding import pipeline
 
-        mesh = jax.make_mesh((4,), ("stage",))
+        mesh = make_mesh((4,), ("stage",))
         rng = np.random.default_rng(2)
         S, M, MB, D = 4, 6, 8, 32
         w = jnp.asarray(rng.standard_normal((S, D, D)) * 0.3, jnp.float32)
@@ -320,6 +321,7 @@ def test_sharded_train_step_runs_and_matches_single_device():
     run_devices("""
         import jax, jax.numpy as jnp, numpy as np
         from repro import configs
+        from repro.compat import make_mesh
         from repro.configs.base import reduced
         from repro.models import api
         from repro.train import train_step as ts
@@ -327,7 +329,7 @@ def test_sharded_train_step_runs_and_matches_single_device():
 
         cfg = reduced(configs.get_config("tinyllama-1.1b"), remat=True)
         options = ts.StepOptions(accum_steps=2, lr=1e-3, total_steps=50)
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         init_fn, step, st_sh = ts.make_train_step(cfg, options, mesh,
                                                   donate=False)
         state = jax.device_put(init_fn(jax.random.key(0)), st_sh)
@@ -339,7 +341,7 @@ def test_sharded_train_step_runs_and_matches_single_device():
         assert float(m2["loss"]) < float(m1["loss"]) + 1.0
 
         # single-device reference: same init, same batch, same update
-        mesh1 = jax.make_mesh((1, 1), ("data", "model"))
+        mesh1 = make_mesh((1, 1), ("data", "model"))
         init1, step1, sh1 = ts.make_train_step(cfg, options, mesh1,
                                                donate=False)
         s1 = jax.device_put(init1(jax.random.key(0)), sh1)
@@ -354,6 +356,7 @@ def test_elastic_checkpoint_restore_across_meshes():
     run_devices("""
         import tempfile, jax, jax.numpy as jnp, numpy as np
         from repro import configs
+        from repro.compat import make_mesh
         from repro.configs.base import reduced
         from repro.train import train_step as ts, checkpoint as ckpt
         from repro.data import synthetic
@@ -363,7 +366,7 @@ def test_elastic_checkpoint_restore_across_meshes():
         d = tempfile.mkdtemp()
         mgr = ckpt.CheckpointManager(d)
 
-        mesh_a = jax.make_mesh((2, 4), ("data", "model"))
+        mesh_a = make_mesh((2, 4), ("data", "model"))
         init_fn, step_a, sh_a = ts.make_train_step(cfg, options, mesh_a,
                                                    donate=False)
         state = jax.device_put(init_fn(jax.random.key(0)), sh_a)
@@ -373,7 +376,7 @@ def test_elastic_checkpoint_restore_across_meshes():
         mgr.save(state, step=1)
 
         # restore onto a DIFFERENT mesh shape (elastic rescale)
-        mesh_b = jax.make_mesh((8, 1), ("data", "model"))
+        mesh_b = make_mesh((8, 1), ("data", "model"))
         init_b, step_b, sh_b = ts.make_train_step(cfg, options, mesh_b,
                                                   donate=False)
         target = jax.eval_shape(init_b, jax.random.key(0))

@@ -275,6 +275,22 @@ def test_plane_unroll_is_bit_identical(unroll):
     np.testing.assert_array_equal(sk_base, base)
 
 
+@pytest.mark.parametrize("rows,cols", [(1, 128), (3, 256), (8, 512),
+                                       (16, 128)])
+def test_lane_table_map_equals_take(rows, cols):
+    """The Mosaic-lowerable table map (two 128-lane halves, lane gather,
+    half select) is `jnp.take` on the 256-entry table, bit for bit —
+    every index value, and row counts below one sublane tile."""
+    rng = np.random.default_rng(rows * cols)
+    tbl = rng.integers(-128, 128, 256).astype(np.int8)
+    idx = np.concatenate([np.arange(256), rng.integers(0, 256, rows * cols)])
+    idx = idx[:rows * cols].reshape(rows, cols).astype(np.int32)
+    halves = [jnp.asarray(tbl[None, h:h + 128], jnp.int32) for h in (0, 128)]
+    got = np.asarray(qk._lane_table_map(*halves, jnp.asarray(idx)))
+    assert got.dtype == np.int8
+    np.testing.assert_array_equal(got, tbl[idx])
+
+
 def test_skinny_vmem_scales_with_true_m():
     """The skinny working set must scale with the true row count — the
     whole point of the decode kernel is never paying the 128-row pad."""

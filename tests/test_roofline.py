@@ -6,6 +6,8 @@ import pytest
 
 from repro.roofline import analysis, hlo_parse
 
+V5E = analysis.chip_peaks("TPU v5 lite")
+
 
 def _compile_text(fn, *args):
     return jax.jit(fn).lower(*args).compile().as_text()
@@ -122,7 +124,7 @@ def test_parse_collectives_scalar_and_unknown_dtype():
 def test_terms_and_bottleneck():
     t = analysis.RooflineTerms(
         flops=1e18, hbm_bytes=1e15, collective_bytes=1e14,
-        collectives={}, chips=256, model_flops=5e17)
+        collectives={}, chips=256, model_flops=5e17, peaks=V5E)
     assert t.compute_s == pytest.approx(1e18 / (256 * 197e12))
     assert t.memory_s == pytest.approx(1e15 / (256 * 819e9))
     assert t.collective_s == pytest.approx(1e14 / (256 * 50e9))
@@ -133,17 +135,17 @@ def test_terms_and_bottleneck():
 def test_terms_bottleneck_variants_and_ratios():
     mem = analysis.RooflineTerms(
         flops=1e12, hbm_bytes=1e15, collective_bytes=0.0, collectives={},
-        chips=1, model_flops=1e12)
+        chips=1, model_flops=1e12, peaks=V5E)
     assert mem.bottleneck == "memory"
     coll = analysis.RooflineTerms(
         flops=1e12, hbm_bytes=1e9, collective_bytes=1e15, collectives={},
-        chips=1, model_flops=1e12)
+        chips=1, model_flops=1e12, peaks=V5E)
     assert coll.bottleneck == "collective"
     # useful_flops_ratio is MODEL/HLO; remat (HLO > MODEL) gives < 1
     assert coll.useful_flops_ratio == pytest.approx(1.0)
     remat = analysis.RooflineTerms(
         flops=2e12, hbm_bytes=1e9, collective_bytes=0.0, collectives={},
-        chips=1, model_flops=1e12)
+        chips=1, model_flops=1e12, peaks=V5E)
     assert remat.useful_flops_ratio == pytest.approx(0.5)
     assert remat.roofline_fraction == pytest.approx(0.5)
 
@@ -151,7 +153,7 @@ def test_terms_bottleneck_variants_and_ratios():
 def test_terms_zero_edges():
     z = analysis.RooflineTerms(
         flops=0.0, hbm_bytes=0.0, collective_bytes=0.0, collectives={},
-        chips=4, model_flops=0.0)
+        chips=4, model_flops=0.0, peaks=V5E)
     assert z.useful_flops_ratio == 0.0
     assert z.roofline_fraction == 0.0
     assert z.roofline_fraction_kernel_adj == 0.0
@@ -161,6 +163,7 @@ def test_terms_as_dict_round_trip():
     t = analysis.RooflineTerms(
         flops=1e18, hbm_bytes=1e15, collective_bytes=1e14,
         collectives={"all-reduce": 1e14}, chips=256, model_flops=5e17,
+        peaks=V5E,
         tagged_bytes=2e14, kernel_io_bytes=1e13)
     d = t.as_dict()
     assert {"flops", "hbm_bytes", "collective_bytes", "collectives",
@@ -178,11 +181,36 @@ def test_terms_as_dict_round_trip():
 def test_kernel_adjustment_reduces_memory_term():
     t = analysis.RooflineTerms(
         flops=1e18, hbm_bytes=1e16, collective_bytes=0.0, collectives={},
-        chips=256, model_flops=5e17, tagged_bytes=8e15,
+        chips=256, model_flops=5e17, peaks=V5E, tagged_bytes=8e15,
         kernel_io_bytes=1e14)
     assert t.hbm_bytes_kernel_adj == pytest.approx(2e15 + 1e14)
     assert t.memory_kernel_adj_s < t.memory_s
     assert t.roofline_fraction_kernel_adj >= t.roofline_fraction
+
+
+def test_chip_peaks_table():
+    """Published v5e peaks (Google Cloud, "TPU v5e"); an unknown chip kind
+    is an error, not a default."""
+    assert V5E.bf16_flops == 197e12
+    assert V5E.int8_ops == 393e12
+    assert V5E.hbm_bw == 819e9
+    with pytest.raises(ValueError, match="no published peaks"):
+        analysis.chip_peaks("TPU v99")
+
+
+def test_unknown_tpu_kind_fails_dispatch(monkeypatch):
+    """The roofline dispatch plan on a TPU prices GEMMs at the local chip's
+    peaks and refuses a chip kind it has no peaks for."""
+    import types
+    from repro.kernels import dispatch
+
+    fake = types.SimpleNamespace(device_kind="TPU v99", platform="tpu")
+    monkeypatch.setattr(jax, "devices", lambda *a: [fake])
+    with pytest.raises(ValueError, match="TPU v99"):
+        dispatch._roofline_plan(256, 2048, 2048, 3, 256, 512, 256)
+    fake.device_kind = "TPU v5 lite"
+    plan = dispatch._roofline_plan(256, 2048, 2048, 3, 256, 512, 256)
+    assert plan.source == "roofline"
 
 
 def test_model_flops_shapes():

@@ -269,3 +269,21 @@ def test_shapes_classification_learnable_structure():
     m0 = x[y == 0].mean(axis=0)
     m1 = x[y == 1].mean(axis=0) if (y == 1).any() else m0
     assert np.abs(m0 - m1).max() > 0.3
+
+
+def test_restart_of_finished_run_exits_cleanly():
+    """A restart that resumes from the last step's checkpoint has no step
+    left to run; it must still finish (the crash-restart test hits this
+    when the first run completes before it is killed)."""
+    with tempfile.TemporaryDirectory() as d:
+        env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
+        args = [sys.executable, "-m", "repro.launch.train",
+                "--arch", "tinyllama-1.1b", "--reduced", "--steps", "2",
+                "--batch", "2", "--seq", "32", "--ckpt-dir", d,
+                "--ckpt-every", "1"]
+        for _ in range(2):
+            out = subprocess.run(args, env=env, capture_output=True,
+                                 text=True, timeout=600)
+            assert out.returncode == 0, out.stdout + out.stderr
+        assert "resumed from step 2" in out.stdout
+        assert "done: no steps left" in out.stdout
