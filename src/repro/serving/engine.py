@@ -35,6 +35,13 @@ deadline eviction (`"deadline"`), and exception-safe admission — a crash
 inside prefill re-queues the victim request before propagating, so a
 fleet supervisor draining `pending_requests()` off the dead engine never
 loses it.
+
+Observability: each phase of `step()` runs inside a profiler span
+(`jax.profiler.TraceAnnotation`, named by the `SPAN_*` constants below,
+all under the `engine.` prefix), so a trace taken with
+`jax.profiler.start_trace` puts the host's phases on the device trace's
+clock.  Without a profiler session a span costs well under a
+microsecond and records nothing.
 """
 
 from __future__ import annotations
@@ -46,6 +53,7 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig
 from repro.models import api
@@ -56,6 +64,39 @@ from repro.serving.types import (Completion, Request, SamplingParams,
                                  SpecStats)
 from repro.sharding import ctx, rules
 from repro.train import train_step as ts
+
+#: the whole of one `Engine.step()`
+SPAN_STEP = "engine.step"
+#: ready-marking, deadline expiry and load shedding at the tick's start
+SPAN_SCHEDULE = "engine.schedule"
+#: one admission; tagged with `request_id`, `prompt_len` and `bucket`
+#: (the prefill's padded length) when a profiler is recording.  On
+#: `PagedEngine`'s chunked path each later chunk is one more such span,
+#: without counters, around that chunk and, after the last, the install
+SPAN_ADMIT = "engine.admit"
+#: prompt padding, the prefill dispatch and the wait for it
+SPAN_ADMIT_PREFILL = "engine.admit.prefill"
+#: the first-token sample dispatch and the request's state install
+#: into the decode arena
+SPAN_ADMIT_INSTALL = "engine.admit.install"
+#: the first token's read-back and emit
+SPAN_ADMIT_FIRST_TOKEN = "engine.admit.first_token"
+#: the decode step's dispatch
+SPAN_DECODE = "engine.decode"
+#: the wait for the decode step's tokens and their copy to the host
+SPAN_READBACK = "engine.readback"
+#: energy metering and the per-slot emit loop, with the streaming
+#: callback and evictions
+SPAN_EMIT = "engine.emit"
+
+
+def admission_counters(span: TraceAnnotation, request: Request,
+                       prompt_len: int, bucket: int) -> None:
+    """Tag an `engine.admit` span with the request and the prompt's
+    length before and after padding (only while a profiler records)."""
+    if span.is_enabled():
+        span.set_metadata(request_id=request.request_id,
+                          prompt_len=prompt_len, bucket=bucket)
 
 
 class _Slot:
@@ -341,54 +382,68 @@ class Engine:
                 1, cfg.n_img_tokens, cfg.d_model)
         return out
 
+    def _prefill_padded(self, request: Request, bucket: int
+                        ) -> tuple[jax.Array, dict, dict]:
+        """Pad the prompt to `bucket`, run the prefill and wait for it,
+        inside the `engine.admit.prefill` span; returns (logits, the
+        request's cache, the prefill extras)."""
+        n = len(request.tokens)
+        with TraceAnnotation(SPAN_ADMIT_PREFILL):
+            padded = np.zeros((1, bucket), np.int32)
+            padded[0, :n] = np.asarray(request.tokens, np.int32)
+            extras = self._prefill_extras(request)
+            t0 = time.perf_counter()
+            logits, req_cache = self._prefill(
+                self.exec_params, jnp.asarray(padded), extras,
+                true_len=jnp.asarray([n], jnp.int32))
+            jax.block_until_ready(logits)
+            dt = time.perf_counter() - t0
+            self._prefill_s += dt
+            if self.meter is not None:
+                self.meter.on_prefill(request.request_id, dt)
+        return logits, req_cache, extras
+
     def _admit(self, request: Request, ready_wall: float,
                slot_id: int) -> None:
         sp = request.sampling
-        prompt = np.asarray(request.tokens, np.int32)
-        n = prompt.shape[0]
+        n = len(request.tokens)
         bucket = next(b for b in self.buckets if b >= n)
-        padded = np.zeros((1, bucket), np.int32)
-        padded[0, :n] = prompt
-        extras = self._prefill_extras(request)
-        t0 = time.perf_counter()
-        logits, req_cache = self._prefill(
-            self.exec_params, jnp.asarray(padded), extras,
-            true_len=jnp.asarray([n], jnp.int32))
-        jax.block_until_ready(logits)
-        dt = time.perf_counter() - t0
-        self._prefill_s += dt
-        if self.meter is not None:
-            self.meter.on_prefill(request.request_id, dt)
-        key = self._request_key(sp)
-        first = self._first(logits.astype(jnp.float32),
-                            jnp.asarray([sp.temperature], jnp.float32),
-                            jnp.asarray([sp.top_k], jnp.int32),
-                            key[None])
-        self._admitted += 1
+        with TraceAnnotation(SPAN_ADMIT) as span:
+            admission_counters(span, request, n, bucket)
+            logits, req_cache, extras = self._prefill_padded(request, bucket)
+            with TraceAnnotation(SPAN_ADMIT_INSTALL):
+                key = self._request_key(sp)
+                first = self._first(
+                    logits.astype(jnp.float32),
+                    jnp.asarray([sp.temperature], jnp.float32),
+                    jnp.asarray([sp.top_k], jnp.int32), key[None])
+                self._admitted += 1
 
-        self._arena.cache = self._state["cache"]
-        self._arena.insert(req_cache, slot_id)
-        self._state["cache"] = self._arena.cache
-        at = jnp.asarray(slot_id)
-        self._state = dict(
-            self._state,
-            tok=self._state["tok"].at[at].set(first[:, None][0]),
-            temp=self._state["temp"].at[at].set(sp.temperature),
-            topk=self._state["topk"].at[at].set(sp.top_k),
-            rng=self._state["rng"].at[at].set(key))
-        if "img" in self._state:
-            self._state["img"] = jax.lax.dynamic_update_slice_in_dim(
-                self._state["img"], extras["img_embeds"].astype(
-                    self._state["img"].dtype), slot_id, axis=0)
-        # re-commit the canonical shardings after the out-of-jit updates
-        # (slot insert / .at scatters), so the decode step's jit cache
-        # always keys on one sharding layout
-        self._state = jax.device_put(self._state, self._state_sh)
+                self._arena.cache = self._state["cache"]
+                self._arena.insert(req_cache, slot_id)
+                self._state["cache"] = self._arena.cache
+                at = jnp.asarray(slot_id)
+                self._state = dict(
+                    self._state,
+                    tok=self._state["tok"].at[at].set(first[:, None][0]),
+                    temp=self._state["temp"].at[at].set(sp.temperature),
+                    topk=self._state["topk"].at[at].set(sp.top_k),
+                    rng=self._state["rng"].at[at].set(key))
+                if "img" in self._state:
+                    self._state["img"] = jax.lax.dynamic_update_slice_in_dim(
+                        self._state["img"], extras["img_embeds"].astype(
+                            self._state["img"].dtype), slot_id, axis=0)
+                # re-commit the canonical shardings after the out-of-jit
+                # updates (slot insert / .at scatters), so the decode
+                # step's jit cache always keys on one sharding layout
+                self._state = jax.device_put(self._state, self._state_sh)
 
-        slot = _Slot(request, n, self._tick, ready_wall, self._admitted)
-        slot.first_wall = time.perf_counter()
-        self._slots[slot_id] = slot
-        self._emit(slot_id, int(first[0]))
+            with TraceAnnotation(SPAN_ADMIT_FIRST_TOKEN):
+                slot = _Slot(request, n, self._tick, ready_wall,
+                             self._admitted)
+                slot.first_wall = time.perf_counter()
+                self._slots[slot_id] = slot
+                self._emit(slot_id, int(first[0]))
 
     # --- token accounting / eviction -------------------------------------
 
@@ -498,44 +553,50 @@ class Engine:
         """One engine tick: shed dead-on-arrival requests, admit due
         requests into free slots, then run one decode step across the
         whole arena."""
-        now = self._tick
-        self._sched.note_ready(now, time.perf_counter())
-        for request in self._sched.pop_expired(now):
-            self._shed(request)
-        while self._free:
-            request = self._sched.pop_ready(now)
-            if request is None:
-                break
-            ready_wall = self._sched.ready_wall(request.request_id)
-            slot_id = self._free.pop()
-            try:
-                self._admit(request, ready_wall, slot_id)
-            except Exception:
-                # crash mid-prefill/insert: restore the host-side queue
-                # state so pending_requests() still drains the victim —
-                # the supervisor re-queues it elsewhere (device state
-                # dies with the engine)
-                if self._slots[slot_id] is None:
-                    self._free.append(slot_id)
-                    self._sched.restore(request, ready_wall)
-                raise
-        if self.n_active:
-            t0 = time.perf_counter()
-            self._state, tok = self._decode(self.exec_params, self._state)
-            self._decode_steps += 1
-            tok_host = np.asarray(tok)          # syncs the step
-            dt = time.perf_counter() - t0
-            self._decode_s += dt
-            if self.meter is not None:
-                # charge BEFORE emitting: a request evicted this step
-                # must carry this step's share of the energy
-                self.meter.on_decode(
-                    dt, [s.request.request_id for s in self._slots
-                         if s is not None], self.capacity)
-            for slot_id in range(self.capacity):
-                if self._slots[slot_id] is not None:
-                    self._emit(slot_id, int(tok_host[slot_id]))
-        self._tick += 1
+        with TraceAnnotation(SPAN_STEP):
+            now = self._tick
+            with TraceAnnotation(SPAN_SCHEDULE):
+                self._sched.note_ready(now, time.perf_counter())
+                for request in self._sched.pop_expired(now):
+                    self._shed(request)
+            while self._free:
+                request = self._sched.pop_ready(now)
+                if request is None:
+                    break
+                ready_wall = self._sched.ready_wall(request.request_id)
+                slot_id = self._free.pop()
+                try:
+                    self._admit(request, ready_wall, slot_id)
+                except Exception:
+                    # crash mid-prefill/insert: restore the host-side
+                    # queue state so pending_requests() still drains the
+                    # victim — the supervisor re-queues it elsewhere
+                    # (device state dies with the engine)
+                    if self._slots[slot_id] is None:
+                        self._free.append(slot_id)
+                        self._sched.restore(request, ready_wall)
+                    raise
+            if self.n_active:
+                t0 = time.perf_counter()
+                with TraceAnnotation(SPAN_DECODE):
+                    self._state, tok = self._decode(self.exec_params,
+                                                    self._state)
+                self._decode_steps += 1
+                with TraceAnnotation(SPAN_READBACK):
+                    tok_host = np.asarray(tok)          # syncs the step
+                dt = time.perf_counter() - t0
+                self._decode_s += dt
+                with TraceAnnotation(SPAN_EMIT):
+                    if self.meter is not None:
+                        # charge BEFORE emitting: a request evicted this
+                        # step must carry this step's share of the energy
+                        self.meter.on_decode(
+                            dt, [s.request.request_id for s in self._slots
+                                 if s is not None], self.capacity)
+                    for slot_id in range(self.capacity):
+                        if self._slots[slot_id] is not None:
+                            self._emit(slot_id, int(tok_host[slot_id]))
+            self._tick += 1
 
     def run_until_complete(self) -> list[Completion]:
         """Drive step() until the queue and the arena are both empty;

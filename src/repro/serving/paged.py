@@ -50,9 +50,11 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig
 from repro.models import api
+from repro.serving import engine as eng
 from repro.serving import sampling
 from repro.serving.arena import PagedArena
 from repro.serving.engine import Engine, _Slot
@@ -421,29 +423,15 @@ class PagedEngine(Engine):
         token sampling (same bucket, same ops, same RNG), then a paged
         insert instead of a slot insert."""
         sp = request.sampling
-        prompt = np.asarray(request.tokens, np.int32)
-        n = prompt.shape[0]
+        n = len(request.tokens)
         bucket = next(b for b in self.buckets if b >= n)
-        padded = np.zeros((1, bucket), np.int32)
-        padded[0, :n] = prompt
-        extras = self._prefill_extras(request)
-        t0 = time.perf_counter()
-        logits, req_cache = self._prefill(
-            self.exec_params, jnp.asarray(padded), extras,
-            true_len=jnp.asarray([n], jnp.int32))
-        jax.block_until_ready(logits)
-        dt = time.perf_counter() - t0
-        self._prefill_s += dt
-        if self.meter is not None:
-            self.meter.on_prefill(request.request_id, dt)
-        key = self._request_key(sp)
-        first = self._first(logits.astype(jnp.float32),
-                            jnp.asarray([sp.temperature], jnp.float32),
-                            jnp.asarray([sp.top_k], jnp.int32),
-                            key[None])
-        self._admitted += 1
-        self._install(request, req_cache, slot_id, lease, n, extras, sp,
-                      key, first, ready_wall, digest)
+        with TraceAnnotation(eng.SPAN_ADMIT) as span:
+            eng.admission_counters(span, request, n, bucket)
+            logits, req_cache, extras = self._prefill_padded(request, bucket)
+            key = self._request_key(sp)
+            self._admitted += 1
+            self._install(request, req_cache, slot_id, lease, n, extras,
+                          sp, key, logits, ready_wall, digest)
 
     def _start_chunked(self, request: Request, ready_wall: float,
                        slot_id: int, lease: PageLease, digest: str) -> None:
@@ -453,71 +441,84 @@ class PagedEngine(Engine):
         sp = request.sampling
         prompt = np.asarray(request.tokens, np.int32)
         c = self.prefill_chunk
-        extras = self._prefill_extras(request)
-        key = self._request_key(sp)
-        self._admitted += 1
-        t0 = time.perf_counter()
-        logits, workspace = self._prefill(
-            self.exec_params, jnp.asarray(prompt[None, :c]), extras,
-            true_len=jnp.asarray([c], jnp.int32))
-        jax.block_until_ready(logits)
-        dt = time.perf_counter() - t0
-        self._prefill_s += dt
-        self._chunks += 1
-        if self.meter is not None:
-            self.meter.on_prefill(request.request_id, dt)
-        slot = _Slot(request, len(prompt), self._tick, ready_wall,
-                     self._admitted)
-        slot.prefilling = True
-        if self.draft_tier is not None:
-            slot.spec_counts = {"proposed": 0, "accepted": 0,
-                                "corrections": 0}
-        self._slots[slot_id] = slot
-        self._jobs.append(_ChunkJob(request, slot_id, lease, digest, key,
-                                    extras, workspace, c))
-
-    def _install(self, request: Request, req_cache: dict, slot_id: int,
-                 lease: PageLease, n: int, extras: dict, sp, key,
-                 first, ready_wall: float, digest: str,
-                 slot: _Slot | None = None) -> None:
-        """Common tail of both admission paths: paged insert, device
-        row updates, prefix registration, slot record, first emit."""
-        rid = request.request_id
-        flat_idx = self._flat_idx(lease, lease.hit_tokens, n)
-        self._arena.cache = self._state["cache"]
-        self._arena.insert(req_cache, slot_id, flat_idx)
-        self._state["cache"] = self._arena.cache
-        row = np.zeros((self._arena.max_pages,), np.int32)
-        row[:len(lease.pages)] = lease.pages
-        at = jnp.asarray(slot_id)
-        self._state = dict(
-            self._state,
-            table=self._state["table"].at[at].set(jnp.asarray(row)),
-            tok=self._state["tok"].at[at].set(first[:, None][0]),
-            temp=self._state["temp"].at[at].set(sp.temperature),
-            topk=self._state["topk"].at[at].set(sp.top_k),
-            rng=self._state["rng"].at[at].set(key))
-        if "img" in self._state:
-            self._state["img"] = jax.lax.dynamic_update_slice_in_dim(
-                self._state["img"], extras["img_embeds"].astype(
-                    self._state["img"].dtype), slot_id, axis=0)
-        self._state = jax.device_put(self._state, self._state_sh)
-        if self.prefix_cache:
-            self._alloc.register_prefix(rid, tuple(request.tokens), digest)
-        if slot is None:
-            slot = _Slot(request, n, self._tick, ready_wall,
+        # every chunk runs at length c, the last one padded
+        padded_len = -(-len(prompt) // c) * c
+        with TraceAnnotation(eng.SPAN_ADMIT) as span:
+            eng.admission_counters(span, request, len(prompt), padded_len)
+            with TraceAnnotation(eng.SPAN_ADMIT_PREFILL):
+                extras = self._prefill_extras(request)
+                key = self._request_key(sp)
+                self._admitted += 1
+                t0 = time.perf_counter()
+                logits, workspace = self._prefill(
+                    self.exec_params, jnp.asarray(prompt[None, :c]), extras,
+                    true_len=jnp.asarray([c], jnp.int32))
+                jax.block_until_ready(logits)
+                dt = time.perf_counter() - t0
+                self._prefill_s += dt
+                self._chunks += 1
+                if self.meter is not None:
+                    self.meter.on_prefill(request.request_id, dt)
+            slot = _Slot(request, len(prompt), self._tick, ready_wall,
                          self._admitted)
+            slot.prefilling = True
             if self.draft_tier is not None:
                 slot.spec_counts = {"proposed": 0, "accepted": 0,
                                     "corrections": 0}
             self._slots[slot_id] = slot
-        slot.prefilling = False
-        slot.first_wall = time.perf_counter()
-        slot.first_tick = self._tick
-        if slot.spec_counts is not None:
-            slot.spec_counts["corrections"] += 1
-            self._spec_totals["corrections"] += 1
-        self._emit(slot_id, int(first[0]))
+            self._jobs.append(_ChunkJob(request, slot_id, lease, digest,
+                                        key, extras, workspace, c))
+
+    def _install(self, request: Request, req_cache: dict, slot_id: int,
+                 lease: PageLease, n: int, extras: dict, sp, key,
+                 logits: jax.Array, ready_wall: float, digest: str,
+                 slot: _Slot | None = None) -> None:
+        """Common tail of both admission paths: first-token sample from
+        the last prompt position's `logits`, paged insert, device row
+        updates, prefix registration, slot record, first emit."""
+        rid = request.request_id
+        with TraceAnnotation(eng.SPAN_ADMIT_INSTALL):
+            first = self._first(logits.astype(jnp.float32),
+                                jnp.asarray([sp.temperature], jnp.float32),
+                                jnp.asarray([sp.top_k], jnp.int32),
+                                key[None])
+            flat_idx = self._flat_idx(lease, lease.hit_tokens, n)
+            self._arena.cache = self._state["cache"]
+            self._arena.insert(req_cache, slot_id, flat_idx)
+            self._state["cache"] = self._arena.cache
+            row = np.zeros((self._arena.max_pages,), np.int32)
+            row[:len(lease.pages)] = lease.pages
+            at = jnp.asarray(slot_id)
+            self._state = dict(
+                self._state,
+                table=self._state["table"].at[at].set(jnp.asarray(row)),
+                tok=self._state["tok"].at[at].set(first[:, None][0]),
+                temp=self._state["temp"].at[at].set(sp.temperature),
+                topk=self._state["topk"].at[at].set(sp.top_k),
+                rng=self._state["rng"].at[at].set(key))
+            if "img" in self._state:
+                self._state["img"] = jax.lax.dynamic_update_slice_in_dim(
+                    self._state["img"], extras["img_embeds"].astype(
+                        self._state["img"].dtype), slot_id, axis=0)
+            self._state = jax.device_put(self._state, self._state_sh)
+            if self.prefix_cache:
+                self._alloc.register_prefix(rid, tuple(request.tokens),
+                                            digest)
+        with TraceAnnotation(eng.SPAN_ADMIT_FIRST_TOKEN):
+            if slot is None:
+                slot = _Slot(request, n, self._tick, ready_wall,
+                             self._admitted)
+                if self.draft_tier is not None:
+                    slot.spec_counts = {"proposed": 0, "accepted": 0,
+                                        "corrections": 0}
+                self._slots[slot_id] = slot
+            slot.prefilling = False
+            slot.first_wall = time.perf_counter()
+            slot.first_tick = self._tick
+            if slot.spec_counts is not None:
+                slot.spec_counts["corrections"] += 1
+                self._spec_totals["corrections"] += 1
+            self._emit(slot_id, int(first[0]))
 
     # --- chunked-prefill advance ------------------------------------------
 
@@ -539,36 +540,36 @@ class PagedEngine(Engine):
 
     def _advance_one(self, job: _ChunkJob) -> bool:
         """Run one chunk; returns True when the prefill finished (first
-        token emitted, request joins decode this tick)."""
-        prompt = np.asarray(job.request.tokens, np.int32)
-        n = prompt.shape[0]
-        c = self.prefill_chunk
-        take = min(c, n - job.pos)
-        padded = np.zeros((1, c), np.int32)
-        padded[0, :take] = prompt[job.pos:job.pos + take]
-        t0 = time.perf_counter()
-        logits, job.workspace = self._chunk(
-            self.exec_params, job.workspace, jnp.asarray(padded),
-            job.extras, jnp.asarray([take], jnp.int32))
-        jax.block_until_ready(logits)
-        dt = time.perf_counter() - t0
-        self._prefill_s += dt
-        self._chunks += 1
-        if self.meter is not None:
-            self.meter.on_prefill(job.request.request_id, dt)
-        job.pos += take
-        if job.pos < n:
-            return False
-        sp = job.request.sampling
-        first = self._first(logits[:, take - 1].astype(jnp.float32),
-                            jnp.asarray([sp.temperature], jnp.float32),
-                            jnp.asarray([sp.top_k], jnp.int32),
-                            job.key[None])
-        slot = self._slots[job.slot_id]
-        self._install(job.request, job.workspace, job.slot_id, job.lease,
-                      n, job.extras, sp, job.key, first, slot.ready_wall,
-                      job.digest, slot=slot)
-        return True
+        token emitted, request joins decode this tick).  Each later chunk
+        is an `engine.admit` span of its own, without the counters that
+        the first chunk's span carries for the whole admission."""
+        with TraceAnnotation(eng.SPAN_ADMIT):
+            with TraceAnnotation(eng.SPAN_ADMIT_PREFILL):
+                prompt = np.asarray(job.request.tokens, np.int32)
+                n = prompt.shape[0]
+                c = self.prefill_chunk
+                take = min(c, n - job.pos)
+                padded = np.zeros((1, c), np.int32)
+                padded[0, :take] = prompt[job.pos:job.pos + take]
+                t0 = time.perf_counter()
+                logits, job.workspace = self._chunk(
+                    self.exec_params, job.workspace, jnp.asarray(padded),
+                    job.extras, jnp.asarray([take], jnp.int32))
+                jax.block_until_ready(logits)
+                dt = time.perf_counter() - t0
+                self._prefill_s += dt
+                self._chunks += 1
+                if self.meter is not None:
+                    self.meter.on_prefill(job.request.request_id, dt)
+            job.pos += take
+            if job.pos < n:
+                return False
+            slot = self._slots[job.slot_id]
+            self._install(job.request, job.workspace, job.slot_id,
+                          job.lease, n, job.extras, job.request.sampling,
+                          job.key, logits[:, take - 1], slot.ready_wall,
+                          job.digest, slot=slot)
+            return True
 
     # --- eviction ---------------------------------------------------------
 
@@ -632,81 +633,90 @@ class PagedEngine(Engine):
         """One tick: shed expired, advance at most `chunk_budget`
         prefill chunks, admit while slots AND pages allow, then one
         decode (or draft+verify) step over the active lanes."""
-        now = self._tick
-        self._sched.note_ready(now, time.perf_counter())
-        for request in self._sched.pop_expired(now):
-            self._shed(request)
-        self._advance_prefill()
-        self._admit_ready(now)
-        decoding = [i for i, s in enumerate(self._slots)
-                    if s is not None and not s.prefilling]
-        if decoding:
-            if self._draft is not None:
-                self._spec_step(decoding)
-            else:
-                self._decode_step_paged(decoding)
-        self._tick += 1
+        with TraceAnnotation(eng.SPAN_STEP):
+            now = self._tick
+            with TraceAnnotation(eng.SPAN_SCHEDULE):
+                self._sched.note_ready(now, time.perf_counter())
+                for request in self._sched.pop_expired(now):
+                    self._shed(request)
+            self._advance_prefill()
+            self._admit_ready(now)
+            decoding = [i for i, s in enumerate(self._slots)
+                        if s is not None and not s.prefilling]
+            if decoding:
+                if self._draft is not None:
+                    self._spec_step(decoding)
+                else:
+                    self._decode_step_paged(decoding)
+            self._tick += 1
 
     def _decode_step_paged(self, decoding: list[int]) -> None:
         t0 = time.perf_counter()
-        self._state, tok = self._decode(self.exec_params, self._state)
+        with TraceAnnotation(eng.SPAN_DECODE):
+            self._state, tok = self._decode(self.exec_params, self._state)
         self._decode_steps += 1
-        tok_host = np.asarray(tok)
+        with TraceAnnotation(eng.SPAN_READBACK):
+            tok_host = np.asarray(tok)
         dt = time.perf_counter() - t0
         self._decode_s += dt
-        if self.meter is not None:
-            self.meter.on_decode(
-                dt, [self._slots[i].request.request_id for i in decoding],
-                self.capacity)
-        for slot_id in decoding:
-            if self._slots[slot_id] is not None:
-                self._emit(slot_id, int(tok_host[slot_id]))
+        with TraceAnnotation(eng.SPAN_EMIT):
+            if self.meter is not None:
+                self.meter.on_decode(
+                    dt, [self._slots[i].request.request_id
+                         for i in decoding], self.capacity)
+            for slot_id in decoding:
+                if self._slots[slot_id] is not None:
+                    self._emit(slot_id, int(tok_host[slot_id]))
 
     def _spec_step(self, decoding: list[int]) -> None:
         """Draft + verify one speculative step: greedy lanes emit up to
         `spec_k` accepted drafts + 1 correction, sampled lanes emit one
         baseline-stream token, idle/prefilling lanes are frozen
         (k_row = 0)."""
-        kr = np.zeros((self.capacity,), np.int32)
-        for i in decoding:
-            slot = self._slots[i]
-            sp = slot.request.sampling
-            if sp.temperature <= 0.0:
-                kr[i] = min(self.spec_k,
-                            sp.max_new_tokens - len(slot.tokens))
-            else:
-                kr[i] = 1
-        t0 = time.perf_counter()
-        draft = self._draft(self._draft_exec, self._state)
-        self._state, (emitted, m, a) = self._verify(
-            self.exec_params, self._state, draft, jnp.asarray(kr))
-        em = np.asarray(emitted)
-        mh = np.asarray(m)
-        ah = np.asarray(a)
+        with TraceAnnotation(eng.SPAN_DECODE):
+            kr = np.zeros((self.capacity,), np.int32)
+            for i in decoding:
+                slot = self._slots[i]
+                sp = slot.request.sampling
+                if sp.temperature <= 0.0:
+                    kr[i] = min(self.spec_k,
+                                sp.max_new_tokens - len(slot.tokens))
+                else:
+                    kr[i] = 1
+            t0 = time.perf_counter()
+            draft = self._draft(self._draft_exec, self._state)
+            self._state, (emitted, m, a) = self._verify(
+                self.exec_params, self._state, draft, jnp.asarray(kr))
+        with TraceAnnotation(eng.SPAN_READBACK):
+            em = np.asarray(emitted)
+            mh = np.asarray(m)
+            ah = np.asarray(a)
         self._decode_steps += 1
         self._spec_steps += 1
         dt = time.perf_counter() - t0
         self._decode_s += dt
-        if self.meter is not None:
-            self.meter.on_decode(
-                dt, [self._slots[i].request.request_id for i in decoding],
-                self.capacity)
-        for i in decoding:
-            slot = self._slots[i]
-            if slot is None:
-                continue
-            if slot.request.sampling.temperature <= 0.0:
-                slot.spec_counts["proposed"] += int(kr[i])
-                self._spec_totals["proposed"] += int(kr[i])
-            for j in range(int(mh[i])):
-                field = "accepted" if j < int(ah[i]) else "corrections"
-                # count BEFORE emitting: _emit may evict and freeze the
-                # Completion's SpecStats this very token
-                slot.spec_counts[field] += 1
-                self._spec_totals[field] += 1
-                self._emit(i, int(em[i, j]))
-                if self._slots[i] is None:
-                    break
+        with TraceAnnotation(eng.SPAN_EMIT):
+            if self.meter is not None:
+                self.meter.on_decode(
+                    dt, [self._slots[i].request.request_id
+                         for i in decoding], self.capacity)
+            for i in decoding:
+                slot = self._slots[i]
+                if slot is None:
+                    continue
+                if slot.request.sampling.temperature <= 0.0:
+                    slot.spec_counts["proposed"] += int(kr[i])
+                    self._spec_totals["proposed"] += int(kr[i])
+                for j in range(int(mh[i])):
+                    field = ("accepted" if j < int(ah[i])
+                             else "corrections")
+                    # count BEFORE emitting: _emit may evict and freeze
+                    # the Completion's SpecStats this very token
+                    slot.spec_counts[field] += 1
+                    self._spec_totals[field] += 1
+                    self._emit(i, int(em[i, j]))
+                    if self._slots[i] is None:
+                        break
 
     # --- introspection ----------------------------------------------------
 
